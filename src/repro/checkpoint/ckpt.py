@@ -21,13 +21,12 @@ from typing import Any, Optional, Tuple
 import jax
 import numpy as np
 
-from repro import compat
 
 _SEP = "::"
 
 
 def _keystr(path) -> str:
-    return compat.keystr(path, separator=_SEP)
+    return jax.tree_util.keystr(path, simple=True, separator=_SEP)
 
 
 def _flatten(tree) -> dict:

@@ -26,7 +26,6 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.core.planner import HWParams, TPU_V5E
 
@@ -190,7 +189,7 @@ def sharding_plan(cfg: ModelConfig, mesh, shape: ShapeSpec, *,
     param_specs = None
     if params_shape is not None:
         def assign(path, leaf):
-            pstr = compat.keystr(path, separator=".")
+            pstr = jax.tree_util.keystr(path, simple=True, separator=".")
             for rx, rule in _PARAM_RULES:
                 if re.search(rx, pstr):
                     return _param_spec(rule, leaf.shape, axis_sizes, fsdp_axis)
@@ -220,7 +219,7 @@ def sharding_plan(cfg: ModelConfig, mesh, shape: ShapeSpec, *,
         seq_shard = shape.name == "long_500k"
 
         def cache_spec(path, leaf):
-            pstr = compat.keystr(path, separator=".")
+            pstr = jax.tree_util.keystr(path, simple=True, separator=".")
             shp = leaf.shape
             nd = len(shp)
             if re.search(r"attn\.(k|v)$", pstr):

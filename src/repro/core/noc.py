@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.kernels import ref
 from repro.pimsim import params as _pimparams
 
@@ -41,10 +40,6 @@ def _max(a, b):
 COMBINERS = {"add": _add, "max": _max}
 
 
-def _axis_size(axis_name: str) -> int:
-    return compat.axis_size(axis_name)
-
-
 def _is_pow2(n: int) -> bool:
     return n & (n - 1) == 0
 
@@ -55,7 +50,7 @@ def butterfly_all_reduce(tree, axis_name: str, combiner="add"):
     one recursive-doubling schedule.  Falls back to psum for non-pow2 axes
     when the combiner is 'add'."""
     comb = COMBINERS.get(combiner, combiner)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if not _is_pow2(n):
         if combiner == "add":
             return jax.tree.map(lambda a: lax.psum(a, axis_name), tree)
@@ -76,7 +71,7 @@ def tree_reduce(tree, axis_name: str, combiner="add", root: int = 0):
     at step k, nodes at odd multiples of 2^k forward their partial to the
     node 2^k below.  Only the root's value is meaningful afterwards."""
     comb = COMBINERS.get(combiner, combiner)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     assert _is_pow2(n), n
     assert root == 0, "rotate indices for non-zero roots"
     k = 1
@@ -95,7 +90,7 @@ def tree_reduce(tree, axis_name: str, combiner="add", root: int = 0):
 
 def tree_broadcast(tree, axis_name: str, root: int = 0):
     """Binary-tree broadcast from ``root`` — the reduce tree run backwards."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     assert _is_pow2(n) and root == 0
     k = n // 2
     while k >= 1:
@@ -388,7 +383,7 @@ def centralized_softmax(x, axis_name: str):
     """Baseline: gather the full vector to every shard (the NLU round
     trip), compute softmax locally, keep the local slice.  This is the
     all-gather + broadcast traffic pattern of Fig. 5A."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     full = lax.all_gather(x, axis_name, axis=-1, tiled=True)
     y = jax.nn.softmax(full.astype(jnp.float32), axis=-1).astype(x.dtype)
     idx = lax.axis_index(axis_name)

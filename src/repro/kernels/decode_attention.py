@@ -31,6 +31,9 @@ Contract (shared with ``prefill_attention.py``; quoted by docs/kernels.md):
   the live range and an all-foreign row yields the zero-weight partial.
 
 Grid: (B, KvH, n_seq_blocks) — last axis sequential, scratch accumulates.
+The kernels write ``m``/``l`` as ``[B, KvH, G, 1]`` columns: a TPU block's
+last two dims must be (8, 128)-aligned or span the array, which ``(G, 1)``
+does for any G.  The wrappers reshape them to ``[B, H]``.
 """
 from __future__ import annotations
 
@@ -64,7 +67,7 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * scale  # [G, bs]
     kpos = kv_offset + isq * block_s + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = kpos < len_ref[0]
+    valid = kpos < len_ref[ib]
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -80,8 +83,8 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
     def _finalize():
         if return_partials:
             o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
-            m_ref[0, 0] = m_scr[...][:, 0].astype(m_ref.dtype)
-            l_ref[0, 0] = l_scr[...][:, 0].astype(l_ref.dtype)
+            m_ref[0, 0] = m_scr[...]
+            l_ref[0, 0] = l_scr[...]
         else:
             l = jnp.maximum(l_scr[...], 1e-30)
             o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -118,17 +121,18 @@ def _decode(q, k, v, lengths, *, kv_offset: int, block_s: int,
             pl.BlockSpec((1, 1, g, d), lambda ib, ih, isq: (ib, ih, 0, 0)),
             pl.BlockSpec((1, block_s, d), lambda ib, ih, isq, _kvh=kvh: (ib * _kvh + ih, isq, 0)),
             pl.BlockSpec((1, block_s, d), lambda ib, ih, isq, _kvh=kvh: (ib * _kvh + ih, isq, 0)),
-            pl.BlockSpec((1,), lambda ib, ih, isq: (ib,), memory_space=pltpu.SMEM),
+            # whole [B] lengths vector in SMEM (a (1,) block is not tileable)
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, g, d), lambda ib, ih, isq: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, g), lambda ib, ih, isq: (ib, ih, 0)),
-            pl.BlockSpec((1, 1, g), lambda ib, ih, isq: (ib, ih, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda ib, ih, isq: (ib, ih, 0, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda ib, ih, isq: (ib, ih, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, kvh, g, d), out_dt),
-            jax.ShapeDtypeStruct((b, kvh, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, kvh, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
@@ -243,8 +247,8 @@ def _paged_kernel(bt_ref, len_ref, *refs, scale: float, block_s: int,
     def _finalize():
         if return_partials:
             o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
-            m_ref[0, 0] = m_scr[...][:, 0].astype(m_ref.dtype)
-            l_ref[0, 0] = l_scr[...][:, 0].astype(l_ref.dtype)
+            m_ref[0, 0] = m_scr[...]
+            l_ref[0, 0] = l_scr[...]
         else:
             l = jnp.maximum(l_scr[...], 1e-30)
             o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -284,8 +288,8 @@ def _paged_decode(q, k_pages, v_pages, block_tables, lengths, *,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, g, d), lambda ib, ih, ibk, *_: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, g), lambda ib, ih, ibk, *_: (ib, ih, 0)),
-            pl.BlockSpec((1, 1, g), lambda ib, ih, ibk, *_: (ib, ih, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda ib, ih, ibk, *_: (ib, ih, 0, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda ib, ih, ibk, *_: (ib, ih, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
@@ -302,8 +306,8 @@ def _paged_decode(q, k_pages, v_pages, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, kvh, g, d), out_dt),
-            jax.ShapeDtypeStruct((b, kvh, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, kvh, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
         ],
         interpret=interpret,
     )(*prefetch, qh, k_pages, v_pages)

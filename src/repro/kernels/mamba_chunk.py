@@ -30,33 +30,39 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hf_ref, h_scr,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     xc = x_ref[0].astype(jnp.float32)                # [T, P]
-    dtc = dt_ref[0, :, 0].astype(jnp.float32)        # [T]
-    A = a_ref[0]                                     # scalar (SMEM)
+    dt_col = dt_ref[0].astype(jnp.float32)           # [T, 1]
+    A = a_ref[pl.program_id(1)]                      # scalar (SMEM)
     Bc = b_ref[0].astype(jnp.float32)                # [T, N]
     Cc = c_ref[0].astype(jnp.float32)                # [T, N]
     h = h_scr[...]                                   # [P, N]
     t = chunk
 
-    dA = dtc * A                                     # [T], <= 0
-    cum = jnp.cumsum(dA)                             # [T]
-    decay = jnp.exp(cum[:, None] - cum[None, :])     # [T, U]
-    tri = (lax.broadcasted_iota(jnp.int32, (t, t), 0)
-           >= lax.broadcasted_iota(jnp.int32, (t, t), 1))
-    decay = jnp.where(tri, decay, 0.0)
+    # Mosaic has no cumsum or 1-D vectors: prefix sums are masked
+    # reductions over a [T, U] grid, kept as [T, 1] columns / [1, U] rows
+    r = lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    c = lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    tri = r >= c
+    dt_row = jnp.sum(jnp.where(r == c, dt_col, 0.0), axis=0, keepdims=True)
+    dA_col = dt_col * A                              # [T, 1], <= 0
+    dA_row = dt_row * A                              # [1, U]
+    cum_col = jnp.sum(jnp.where(tri, dA_row, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(r <= c, dA_col, 0.0), axis=0, keepdims=True)
+    total = jnp.sum(dA_col, axis=0, keepdims=True)   # [1, 1] = cum[-1]
+    decay = jnp.where(tri, jnp.exp(cum_col - cum_row), 0.0)       # [T, U]
     cb = lax.dot_general(Cc, Bc, (((1,), (1,)), ((), ())),
                          preferred_element_type=jnp.float32)        # [T, U]
-    wmat = decay * cb * dtc[None, :]
+    wmat = decay * cb * dt_row
     y_intra = lax.dot_general(wmat, xc, (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)   # [T, P]
     # inter-chunk: y += exp(cum_t) * Cc_t . h
     ch = lax.dot_general(Cc, h, (((1,), (1,)), ((), ())),
                          preferred_element_type=jnp.float32)        # [T, P]
-    y = y_intra + ch * jnp.exp(cum)[:, None]
+    y = y_intra + ch * jnp.exp(cum_col)
     y_ref[0] = y.astype(y_ref.dtype)
     # state update: h = exp(cum[-1]) h + (dec_rest*dt*x)^T B
-    dec_rest = jnp.exp(cum[-1] - cum) * dtc          # [T]
-    h_new = h * jnp.exp(cum[-1]) + lax.dot_general(
-        xc * dec_rest[:, None], Bc, (((0,), (0,)), ((), ())),
+    dec_rest = jnp.exp(total - cum_col) * dt_col     # [T, 1]
+    h_new = h * jnp.exp(total) + lax.dot_general(
+        xc * dec_rest, Bc, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # [P, N]
     h_scr[...] = h_new
 
@@ -89,7 +95,8 @@ def mamba2_chunked(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = False):
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda ib, ih, ic, _h=h: (ib * _h + ih, ic, 0)),
             pl.BlockSpec((1, chunk, 1), lambda ib, ih, ic, _h=h: (ib * _h + ih, ic, 0)),
-            pl.BlockSpec((1,), lambda ib, ih, ic: (ih,), memory_space=pltpu.SMEM),
+            # whole [H] decay vector in SMEM (a (1,) block is not tileable)
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, n), lambda ib, ih, ic: (ib, ic, 0)),
             pl.BlockSpec((1, chunk, n), lambda ib, ih, ic: (ib, ic, 0)),
         ],

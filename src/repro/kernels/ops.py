@@ -5,7 +5,15 @@ other-> pure-jnp references (kernels/ref.py) — the CPU dry-run lowers these
 tests-> Pallas with ``interpret=True`` against the ref oracle
 
 ``set_mode`` / ``use_mode`` force a path globally (benchmarks flip this);
-the default 'auto' picks by backend platform.
+the default 'auto' picks by backend platform.  'pallas' always compiles the
+kernels for the TPU (off-TPU that fails rather than interpreting);
+'interpret' runs them in the Pallas interpreter.
+
+A few call shapes have no kernel and always take the reference, in every
+mode: ``flash_attention`` with ragged ``lengths`` or a ``q_offset``,
+``paged_prefill_attention`` with a ``window``, ``rwkv6_scan`` /
+``mamba2_scan`` with an initial state.  Each such trace is counted in
+:func:`fallback_stats`, so a caller can assert its path has none.
 """
 from __future__ import annotations
 
@@ -58,7 +66,25 @@ def _use_pallas() -> bool:
 
 
 def _interp() -> bool:
-    return _MODE == "interpret" or (_MODE == "pallas" and jax.default_backend() != "tpu")
+    return _MODE == "interpret"
+
+
+# Trace-time count of kernel-less call shapes that took the reference while
+# the Pallas path was selected, keyed by op name (see the module docstring).
+_FALLBACKS: dict = {}
+
+
+def reset_fallback_stats() -> None:
+    _FALLBACKS.clear()
+
+
+def fallback_stats() -> dict:
+    return dict(_FALLBACKS)
+
+
+def _fallback(op: str) -> None:
+    if _use_pallas():
+        _FALLBACKS[op] = _FALLBACKS.get(op, 0) + 1
 
 
 def using_pallas() -> bool:
@@ -102,6 +128,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                    block_q=block_q, block_k=block_k,
                                    interpret=_interp())
+    _fallback("flash_attention")
     return ref.flash_attention(q, k, v, causal=causal, window=window,
                                lengths=lengths, q_offset=q_offset)
 
@@ -196,6 +223,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, *, q_offset,
             q, k_pages, v_pages, block_table, q_offset=q_offset,
             length=length, q_tile=q_tile, k_scales=k_scales,
             v_scales=v_scales, interpret=_interp())
+    _fallback("paged_prefill_attention")
     k_lin = gather_pages(k_pages, block_table, k_scales)[None]
     v_lin = gather_pages(v_pages, block_table, v_scales)[None]
     return ref.flash_attention(q, k_lin, v_lin, causal=True,
@@ -248,6 +276,7 @@ def rwkv6_scan(r, k, v, w, u, *, s0=None, chunk: int = 32, ref_chunk: int = 16):
     if _use_pallas() and s0 is None:
         return _rwkv.rwkv6_chunked(r, k, v, w, u, chunk=chunk,
                                    interpret=_interp())
+    _fallback("rwkv6_scan")
     if _RWKV_REF_CHUNKED and r.shape[1] >= 2 * ref_chunk:
         return ref.rwkv6_scan_chunked(r, k, v, w, u, s0=s0, chunk=ref_chunk)
     return ref.rwkv6_scan(r, k, v, w, u, s0=s0)
@@ -261,6 +290,7 @@ def mamba2_scan(x, dt, A, B, C, *, h0=None, chunk: int = 64):
     if _use_pallas() and h0 is None:
         return _mamba.mamba2_chunked(x, dt, A, B, C, chunk=chunk,
                                      interpret=_interp())
+    _fallback("mamba2_scan")
     return ref.mamba2_scan(x, dt, A, B, C, h0=h0, chunk=chunk)
 
 

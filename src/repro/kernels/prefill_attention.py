@@ -193,8 +193,8 @@ def _paged_prefill_kernel(bt_ref, qlen_ref, *refs, scale: float,
     def _finalize():
         if return_partials:
             o_ref[0] = acc_scr[...].astype(o_ref.dtype)
-            m_ref[0] = m_scr[...][:, 0].astype(m_ref.dtype)
-            l_ref[0] = l_scr[...][:, 0].astype(l_ref.dtype)
+            m_ref[0] = m_scr[...]
+            l_ref[0] = l_scr[...]
         else:
             l = jnp.maximum(l_scr[...], 1e-30)
             o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -257,8 +257,10 @@ def _paged_prefill(q, k_pages, v_pages, block_table, q_offset, length, *,
         out_specs=[
             pl.BlockSpec((1, t * g, d),
                          lambda ih, iq, ibk, *_: (ih, iq, 0)),
-            pl.BlockSpec((1, t * g), lambda ih, iq, ibk, *_: (ih, iq)),
-            pl.BlockSpec((1, t * g), lambda ih, iq, ibk, *_: (ih, iq)),
+            # [KvH, rows, 1] columns: (t*g, 1) is TPU-tileable, (1, t*g)
+            # on a [KvH, rows] array is not
+            pl.BlockSpec((1, t * g, 1), lambda ih, iq, ibk, *_: (ih, iq, 0)),
+            pl.BlockSpec((1, t * g, 1), lambda ih, iq, ibk, *_: (ih, iq, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((t * g, 1), jnp.float32),
@@ -275,14 +277,14 @@ def _paged_prefill(q, k_pages, v_pages, block_table, q_offset, length, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((kvh, nqt * t * g, d), out_dt),
-            jax.ShapeDtypeStruct((kvh, nqt * t * g), jnp.float32),
-            jax.ShapeDtypeStruct((kvh, nqt * t * g), jnp.float32),
+            jax.ShapeDtypeStruct((kvh, nqt * t * g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((kvh, nqt * t * g, 1), jnp.float32),
         ],
         interpret=interpret,
     )(*prefetch, qh, k_pages, v_pages)
     out = out[:, :c * g]
-    m = m[:, :c * g]
-    l = l[:, :c * g]
+    m = m[:, :c * g, 0]
+    l = l[:, :c * g, 0]
     out = jnp.transpose(out.reshape(kvh, c, g, d), (1, 0, 2, 3))
     m = jnp.transpose(m.reshape(kvh, c, g), (1, 0, 2))
     l = jnp.transpose(l.reshape(kvh, c, g), (1, 0, 2))

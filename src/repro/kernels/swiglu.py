@@ -40,9 +40,23 @@ def _kernel(g_ref, u_ref, o_ref, *, curry_rounds: int):
     o_ref[...] = (g * sig * u).astype(o_ref.dtype)
 
 
-def silu_mul(gate, up, *, block_rows: int = 512, curry_rounds: int = 0,
+# VMEM one grid step may use: the gate/up/out blocks, double-buffered, plus
+# the kernel's f32 temporaries (~4 live [rows, D] f32 values).  The scoped
+# VMEM limit is 16 MiB; a full-row block of d_ff=5632 at 512 rows needs 38 MB.
+DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
+
+
+def _auto_block_rows(d: int, itemsize: int) -> int:
+    per_row = d * (2 * 3 * itemsize + 4 * 4)
+    return max(16, (DEFAULT_VMEM_BUDGET // per_row) // 16 * 16)
+
+
+def silu_mul(gate, up, *, block_rows=None, curry_rounds: int = 0,
              interpret: bool = False):
-    """silu(gate) * up, elementwise; any shape with last dim D."""
+    """silu(gate) * up, elementwise; any shape with last dim D.
+
+    ``block_rows`` (None: the largest multiple of 16 whose step fits
+    ``DEFAULT_VMEM_BUDGET``) rows of full width D per grid step."""
     shape = gate.shape
     d = shape[-1]
     rows = 1
@@ -50,6 +64,8 @@ def silu_mul(gate, up, *, block_rows: int = 512, curry_rounds: int = 0,
         rows *= s
     g2 = gate.reshape(rows, d)
     u2 = up.reshape(rows, d)
+    if block_rows is None:
+        block_rows = _auto_block_rows(d, jnp.dtype(up.dtype).itemsize)
     block_rows = min(block_rows, rows)
     nb = -(-rows // block_rows)
     pad = nb * block_rows - rows

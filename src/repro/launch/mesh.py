@@ -10,13 +10,12 @@ from __future__ import annotations
 import jax
 
 
-# version-compat mesh constructor (handles pre-AxisType jax releases);
-# re-exported here because mesh construction is this module's job
-from repro.compat import make_mesh as compat_mesh  # noqa: E402
-
-
-def _mk(shape, axes):
-    return compat_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated
+    shardings, the semantics the sharding hints and shard_map bodies
+    here are written for)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,14 +25,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     optional — see launch/train.py)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2, n_pod: int = 0):
     """Small mesh for subprocess tests (8 fake devices)."""
     if n_pod:
-        return _mk((n_pod, n_data, n_model), ("pod", "data", "model"))
-    return _mk((n_data, n_model), ("data", "model"))
+        return make_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def chips(mesh) -> int:

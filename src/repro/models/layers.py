@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
 
@@ -190,7 +189,7 @@ def _decode_attn_seqsharded(q, ck, cv, lens, da):
                                                  kv_offset=off)
         return noc.tree_softmax_combine(acc, m, l, seq_ax).astype(qv.dtype)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(dp, seq_ax, None, None),
                   P(dp, seq_ax, None, None), P(dp)),

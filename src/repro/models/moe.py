@@ -15,7 +15,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models import layers
 
@@ -235,7 +234,7 @@ def _moe_apply_ep(p, x, cfg: ModelConfig, ep, capacity_factor=None):
     dspec = P(dp, None, None)
     wg_spec = P(tp_axis, None, fsdp_axis)
     wd_spec = P(tp_axis, fsdp_axis, None)
-    y, aux_v = compat.shard_map(
+    y, aux_v = jax.shard_map(
         body, mesh=mesh,
         in_specs=(dspec, P(), wg_spec, wg_spec, wd_spec),
         out_specs=(dspec, P()), check_vma=False,
@@ -341,7 +340,7 @@ def expert_param_specs(params, expert_axis: str = "expert"):
     from jax.sharding import PartitionSpec as P
 
     def spec(path, _leaf):
-        ks = compat.keystr(path).split(".")
+        ks = jax.tree_util.keystr(path, simple=True, separator=".").split(".")
         if len(ks) >= 2 and ks[-2] == "moe" and ks[-1] in ("w_gate", "w_up",
                                                            "w_down"):
             return P(None, expert_axis)

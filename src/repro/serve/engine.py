@@ -79,7 +79,7 @@ standard production mechanisms:
 * **Sequence-sharded page pool** (``seq_shards=N``) — the physical pool is
   split over an N-device ``seq`` mesh axis; ``BlockAllocator`` places a
   slot's pages round-robin across shards (fill-local under pressure), and
-  decode/prefill dispatch wraps the paged kernels in ``compat.shard_map``:
+  decode/prefill dispatch wraps the paged kernels in ``jax.shard_map``:
   each shard attends only its local pages (foreign entries map to its
   null page and are skipped) and emits ``(acc, m, l)`` partials that
   ``core.noc.tree_softmax_combine`` merges in transit over the ``seq``
@@ -134,11 +134,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core import noc
 from repro.kernels import ops
 from repro.kernels import prefill_attention as pf_kernel
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models.runner import ModelRunner
 from repro.serve.expert_cache import ExpertCache
@@ -711,20 +711,22 @@ class ServeEngine:
         ep = self.expert_parallel or 1
         ndev = jax.device_count()
         if self.seq_shards * ep > ndev:
+            platform = jax.devices()[0].platform
+            hint = ("set XLA_FLAGS=--xla_force_host_platform_device_count="
+                    f"{self.seq_shards * ep} before importing jax"
+                    if platform == "cpu" else
+                    f"run on a host with {self.seq_shards * ep} chips")
             raise ValueError(
                 f"seq_shards={self.seq_shards} x expert_parallel={ep} "
                 f"needs {self.seq_shards * ep} devices but only {ndev} "
-                f"are visible — set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count="
-                f"{self.seq_shards * ep} before importing jax, or shard "
+                f"{platform} device(s) are visible — {hint}, or shard "
                 f"less")
         if self.seq_shards > 1 and ep > 1:
-            self.mesh = compat.make_mesh((self.seq_shards, ep),
-                                         ("seq", "expert"))
+            self.mesh = make_mesh((self.seq_shards, ep), ("seq", "expert"))
         elif self.seq_shards > 1:
-            self.mesh = compat.make_mesh((self.seq_shards,), ("seq",))
+            self.mesh = make_mesh((self.seq_shards,), ("seq",))
         elif self.expert_parallel is not None:
-            self.mesh = compat.make_mesh((ep,), ("expert",))
+            self.mesh = make_mesh((ep,), ("expert",))
         else:
             self.mesh = None
         self._expert_axis = ("expert" if self.expert_parallel is not None
@@ -848,6 +850,20 @@ class ServeEngine:
             self.state = self.runner.init_state(0, block_size, self.dtype)
         else:
             self.state = self.runner.init_dense_state(self.dtype)
+        if self.mesh is not None:
+            # commit pages and params to the mesh once, as the shard_map
+            # dispatch lays them out: each seq shard's pages on its own
+            # device (not gathered on device 0 and resharded every call)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            pspec, sspec, _, _ = self._shard_specs()
+
+            def place(tree, spec):
+                return jax.device_put(tree, jax.tree.map(
+                    lambda sp: NamedSharding(self.mesh, sp), spec,
+                    is_leaf=lambda sp: isinstance(sp, P)))
+
+            self.state = place(self.state, sspec)
+            self.params = place(self.params, pspec)
         self._slot_state_bytes = (self.runner.slot_state_bytes(self.state)
                                   if self.has_slot_state else 0)
         self._n_apps = self.runner.attn_applications if self.paged else 0
@@ -1006,7 +1022,7 @@ class ServeEngine:
                                      seq_axis="seq" if seq else None,
                                      expert_axis=eax, expert_stats=estats)
 
-            smapped = compat.shard_map(
+            smapped = jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(pspec, sspec, P(), P(), tspec, P()),
                 out_specs=(espec, sspec) + ((espec,) if estats else ()),
@@ -1052,7 +1068,7 @@ class ServeEngine:
                                             expert_axis=eax,
                                             expert_stats=estats)
 
-            smapped = compat.shard_map(
+            smapped = jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(pspec, sspec, P(), P(), P(), tspec, P()),
                 out_specs=(espec, sspec) + ((espec,) if estats else ()),

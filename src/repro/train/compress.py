@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 
 
 def quantize_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -39,7 +38,7 @@ def butterfly_allreduce_int8(x: jax.Array, axis_name: str) -> jax.Array:
     Scales are agreed per hop with a pmax (scalar traffic); values travel
     as int8 and are accumulated in int32 then requantized — i.e. the
     Curry-ALU '+=' applied to compressed flits in transit."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert n & (n - 1) == 0, "butterfly needs a power-of-two axis"
     xf = x.astype(jnp.float32)
     k = 1

@@ -14,7 +14,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models import model as M
 from repro.train import optim
@@ -93,7 +92,7 @@ def cross_entropy_noc(logits, labels, mesh, dp_axes, tp_axis, *, mask=None):
         body2 = lambda lg, lb, _mk: body(lg, lb, None)
     else:
         body2 = body
-    return compat.shard_map(body2, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(body2, mesh=mesh, in_specs=in_specs,
                          out_specs=P(), check_vma=False)(*args)
 
 

@@ -100,10 +100,10 @@ def test_straggler_no_false_positive():
 def test_elastic_validate(subproc):
     out = subproc("""
 import jax
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.runtime.elastic import validate_rescale
-old = compat_mesh((4, 2), ('data', 'model'))
-new = compat_mesh((2, 4), ('data', 'model'))
+old = make_mesh((4, 2), ('data', 'model'))
+new = make_mesh((2, 4), ('data', 'model'))
 assert validate_rescale(old, old, global_batch=256) == []
 assert validate_rescale(old, old, global_batch=255) != []   # 255 % 4 != 0
 assert validate_rescale(old, new, global_batch=256) != []   # TP changed

@@ -80,14 +80,14 @@ def test_sharding_plan_divisibility(subproc, arch):
     with a (2,2,2) mesh)."""
     code = f"""
 import jax
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config, TRAIN_4K, DECODE_32K
 from repro.core import mapping
 from repro.models import model
 from repro.train import step as ts
 cfg = get_config({arch!r})
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((2,2,2), ('pod','data','model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,2,2), ('pod','data','model'))
 state = ts.init_state_shaped(cfg)
 sshape = jax.eval_shape(lambda: model.init_decode_state(cfg, DECODE_32K.global_batch, 1024))
 for shape, st_ in ((TRAIN_4K, None), (DECODE_32K, sshape)):

@@ -61,8 +61,8 @@ def test_collective_bytes_counted(subproc):
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch import hlo_analysis as H
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 x = jax.ShapeDtypeStruct((1024, 64), jnp.float32)
 fn = jax.jit(lambda a: a.sum(0), in_shardings=NamedSharding(mesh, P('x', None)),
              out_shardings=NamedSharding(mesh, P()))

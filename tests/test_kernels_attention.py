@@ -116,3 +116,30 @@ def test_flash_row_convexity(rng):
     out = flash_attention(q, k, v, block_q=8, block_k=8, interpret=True)
     assert float(out.max()) <= float(v.max()) + 1e-5
     assert float(out.min()) >= float(v.min()) - 1e-5
+
+
+def test_dispatch_counts_reference_fallbacks(rng):
+    """A call shape with no kernel takes the reference even when kernels are
+    selected; ``ops.fallback_stats`` counts it, and nothing else."""
+    from repro.kernels import ops
+    q = jnp.asarray(rng.normal(size=(1, 8, 2, 16)), jnp.float32)
+    ops.reset_fallback_stats()
+    with ops.use_mode("interpret"):
+        ops.flash_attention(q, q, q, block_q=8, block_k=8)
+        assert ops.fallback_stats() == {}
+        ops.flash_attention(q, q, q, lengths=jnp.array([5]))
+        assert ops.fallback_stats() == {"flash_attention": 1}
+    with ops.use_mode("ref"):
+        ops.flash_attention(q, q, q, lengths=jnp.array([5]))
+    assert ops.fallback_stats() == {"flash_attention": 1}
+    ops.reset_fallback_stats()
+
+
+def test_pallas_mode_compiles_never_interprets():
+    """'pallas' compiles for the TPU; off-TPU it fails instead of quietly
+    switching to the interpreter."""
+    from repro.kernels import ops
+    if jax.default_backend() == "tpu":
+        pytest.skip("on a TPU 'pallas' compiles")
+    with ops.use_mode("pallas"), pytest.raises(ValueError, match="interpret"):
+        ops.rmsnorm(jnp.ones((8, 128)), jnp.ones((128,)))

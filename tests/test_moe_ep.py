@@ -11,8 +11,8 @@ from repro.models import moe
 
 cfg = reduced(get_config('olmoe-1b-7b'))
 cfg = cfg.replace(capacity_factor=float(cfg.n_experts) / cfg.top_k)  # dropless
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 p = moe.moe_init(jax.random.key(0), cfg, dtype=jnp.float32)
 x = jax.random.normal(jax.random.key(1), (4, 12, cfg.d_model), jnp.float32)
 
@@ -46,8 +46,8 @@ from repro.models import moe
 
 cfg = reduced(get_config('qwen2-moe-a2.7b'))
 cfg = cfg.replace(capacity_factor=float(cfg.n_experts) / cfg.top_k)
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((2, 2, 2), ('pod', 'data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
 p = moe.moe_init(jax.random.key(0), cfg, dtype=jnp.float32)
 x = jax.random.normal(jax.random.key(1), (4, 8, cfg.d_model), jnp.float32)
 y_plain, _ = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg))(p, x)

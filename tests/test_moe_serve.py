@@ -17,8 +17,8 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_config, reduced
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models import moe
 from repro.serve import ServeEngine
@@ -201,7 +201,7 @@ def _ep_local_apply(p, x, cfg, cf, n_shards):
     """Run the EP-local dispatch the way the engine does: inside a
     shard_map over an ``("expert",)`` mesh with the expert banks sharded
     and the router replicated."""
-    mesh = compat.make_mesh((n_shards,), ("expert",))
+    mesh = make_mesh((n_shards,), ("expert",))
     pspec = {k: (P("expert") if k in ("w_gate", "w_up", "w_down") else P())
              for k in p}
 
@@ -209,8 +209,8 @@ def _ep_local_apply(p, x, cfg, cf, n_shards):
         return moe.moe_apply(p_loc, x_rep, cfg, capacity_factor=cf,
                              expert_axis="expert", return_stats=True)
 
-    f = compat.shard_map(body, mesh=mesh, in_specs=(pspec, P()),
-                         out_specs=(P(), P()), check_vma=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(pspec, P()),
+                      out_specs=(P(), P()), check_vma=False)
     return f(p, x)
 
 

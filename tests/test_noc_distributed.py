@@ -8,8 +8,8 @@ def test_tree_collectives_match_builtins(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import noc
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 rng = np.random.default_rng(0)
 v = jnp.asarray(rng.normal(size=(8, 4)), jnp.float32)
 for name, fn, want in [
@@ -18,8 +18,7 @@ for name, fn, want in [
     ('tree_add', lambda a: noc.tree_all_reduce(a, 'x'), v.sum(0)),
     ('tree_max', lambda a: noc.tree_all_reduce(a, 'x', 'max'), v.max(0)),
 ]:
-    from repro import compat
-    got = compat.shard_map(fn, mesh=mesh, in_specs=P('x'), out_specs=P('x'),
+    got = jax.shard_map(fn, mesh=mesh, in_specs=P('x'), out_specs=P('x'),
                         check_vma=False)(v)
     err = float(jnp.abs(got - want[None]).max())
     assert err < 1e-5, (name, err)
@@ -33,19 +32,16 @@ def test_distributed_softmax_and_logsumexp(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import noc
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 x = jnp.asarray(np.random.default_rng(1).normal(size=(5, 64)) * 4, jnp.float32)
-from repro import compat
-ds = compat.shard_map(lambda a: noc.distributed_softmax(a, 'x'), mesh=mesh,
+ds = jax.shard_map(lambda a: noc.distributed_softmax(a, 'x'), mesh=mesh,
                    in_specs=P(None, 'x'), out_specs=P(None, 'x'), check_vma=False)
 assert float(jnp.abs(ds(x) - jax.nn.softmax(x, -1)).max()) < 1e-5
-from repro import compat
-dl = compat.shard_map(lambda a: noc.distributed_logsumexp(a, 'x'), mesh=mesh,
+dl = jax.shard_map(lambda a: noc.distributed_logsumexp(a, 'x'), mesh=mesh,
                    in_specs=P(None, 'x'), out_specs=P(None), check_vma=False)
 assert float(jnp.abs(dl(x) - jax.nn.logsumexp(x, -1)).max()) < 1e-5
-from repro import compat
-cs = compat.shard_map(lambda a: noc.centralized_softmax(a, 'x'), mesh=mesh,
+cs = jax.shard_map(lambda a: noc.centralized_softmax(a, 'x'), mesh=mesh,
                    in_specs=P(None, 'x'), out_specs=P(None, 'x'), check_vma=False)
 assert float(jnp.abs(cs(x) - jax.nn.softmax(x, -1)).max()) < 1e-5
 print('OK')
@@ -59,8 +55,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import noc
 from repro.kernels import ref
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 rng = np.random.default_rng(0)
 B,H,D,S = 2,4,16,64
 q = jnp.asarray(rng.normal(size=(B,H,D)), jnp.float32)
@@ -68,8 +64,7 @@ k = jnp.asarray(rng.normal(size=(B,S,H,D)), jnp.float32)
 v = jnp.asarray(rng.normal(size=(B,S,H,D)), jnp.float32)
 want = ref.decode_attention(q, k, v)
 for combiner in (noc.tree_softmax_combine, noc.centralized_softmax_combine):
-    from repro import compat
-    got = compat.shard_map(
+    got = jax.shard_map(
         lambda a,b,c: combiner(*ref.decode_attention_partial(a,b,c), 'x').astype(a.dtype),
         mesh=mesh, in_specs=(P(), P(None,'x'), P(None,'x')), out_specs=P(),
         check_vma=False)(q, k, v)
@@ -84,12 +79,11 @@ def test_int8_butterfly_allreduce(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.train import compress
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 rng = np.random.default_rng(0)
 g = jnp.asarray(rng.normal(size=(8, 256)), jnp.float32)
-from repro import compat
-got = compat.shard_map(lambda a: compress.butterfly_allreduce_int8(a[0], 'x')[None],
+got = jax.shard_map(lambda a: compress.butterfly_allreduce_int8(a[0], 'x')[None],
                     mesh=mesh, in_specs=P('x'), out_specs=P('x'),
                     check_vma=False)(g)
 want = g.mean(0)
@@ -106,8 +100,8 @@ def test_grad_compression_error_feedback(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.train import compress
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 target = jnp.asarray(np.random.default_rng(0).normal(size=(64,)), jnp.float32)
 
 def step(w, err, xs):
@@ -116,8 +110,7 @@ def step(w, err, xs):
         g = {'w': (w - target) * (1.0 + 0.1 * x)}
         synced, e2 = compress.compressed_grad_sync(g, 'x', {'w': e})
         return synced['w'], e2['w']
-    from repro import compat
-    return compat.shard_map(body, mesh=mesh,
+    return jax.shard_map(body, mesh=mesh,
                          in_specs=(P(), P('x'), P('x')), out_specs=(P(), P('x')),
                          check_vma=False)(w, err, xs)
 

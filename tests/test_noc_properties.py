@@ -15,14 +15,13 @@ failures = []
 for n, shape, dtype, comb in itertools.product(
         (2, 4, 8), ((4,), (3, 5), (2, 2, 2)),
         (jnp.float32, jnp.bfloat16), ('add', 'max')):
-    from repro.launch.mesh import compat_mesh
-    mesh = compat_mesh((n,), ('x',))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((n,), ('x',))
     v = jnp.asarray(rng.normal(size=(n,) + shape), dtype)
     want = (v.astype(jnp.float32).sum(0) if comb == 'add'
             else v.astype(jnp.float32).max(0))
     for fn in (noc.butterfly_all_reduce, noc.tree_all_reduce):
-        from repro import compat
-        got = compat.shard_map(lambda a: fn(a, 'x', comb), mesh=mesh,
+        got = jax.shard_map(lambda a: fn(a, 'x', comb), mesh=mesh,
                             in_specs=P('x'), out_specs=P('x'),
                             check_vma=False)(v)
         err = float(jnp.abs(got.astype(jnp.float32)

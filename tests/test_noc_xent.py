@@ -8,8 +8,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.train.step import cross_entropy, cross_entropy_noc
 
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 rng = np.random.default_rng(0)
 B, S, V = 4, 6, 32
 logits = jnp.asarray(rng.normal(size=(B, S, V)) * 3, jnp.float32)
@@ -32,8 +32,8 @@ def test_noc_xent_grads_match(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.train.step import cross_entropy, cross_entropy_noc
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 rng = np.random.default_rng(1)
 B, S, V = 2, 4, 16
 logits = jnp.asarray(rng.normal(size=(B, S, V)), jnp.float32)

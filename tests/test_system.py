@@ -52,8 +52,8 @@ from repro.models import model
 from repro.train import step as ts
 
 cfg = reduced(get_config('granite-3-2b')).replace(vocab_size=256)
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((2, 2, 2), ('pod', 'data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
 shape = ShapeSpec('mini_train', 16, 8, 'train')
 fn, args, in_sh, out_sh, donate, plan = D.build_cell(cfg, shape, mesh)
 with mesh:
@@ -87,12 +87,12 @@ from repro.runtime.elastic import rescale_from_checkpoint
 tree = {'w': jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
         'b': jnp.ones((8,), jnp.float32)}
 d = tempfile.mkdtemp()
-from repro.launch.mesh import compat_mesh
-mesh1 = compat_mesh((4,), ('data',))
+from repro.launch.mesh import make_mesh
+mesh1 = make_mesh((4,), ('data',))
 t1 = jax.device_put(tree, NamedSharding(mesh1, P()))
 save(d, 3, t1)
 
-mesh2 = compat_mesh((2, 2), ('data', 'model'))
+mesh2 = make_mesh((2, 2), ('data', 'model'))
 sh = {'w': NamedSharding(mesh2, P('data', 'model')),
       'b': NamedSharding(mesh2, P('model'))}
 step, t2 = rescale_from_checkpoint(d, jax.eval_shape(lambda: tree), sh)
