@@ -139,6 +139,7 @@ def _decode(q, k, v, lengths, *, kv_offset: int, block_s: int,
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
+        name="decode_attention",
         interpret=interpret,
     )(qh, kh.reshape(b * kvh, ns * block_s, d), vh.reshape(b * kvh, ns * block_s, d), lens)
     return out.reshape(b, h, d), m.reshape(b, h), l.reshape(b, h)
@@ -309,6 +310,7 @@ def _paged_decode(q, k_pages, v_pages, block_tables, lengths, *,
             jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
         ],
+        name="paged_decode_attention",
         interpret=interpret,
     )(*prefetch, qh, k_pages, v_pages)
     return out.reshape(b, h, d), m.reshape(b, h), l.reshape(b, h)

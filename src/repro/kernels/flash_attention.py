@@ -121,6 +121,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(qh, kh, vh)
 

@@ -109,6 +109,7 @@ def mamba2_chunked(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = False):
             jax.ShapeDtypeStruct((bt, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
+        name="mamba2_chunk_scan",
         interpret=interpret,
     )(xh, dth, A.astype(jnp.float32), B, C)
 

@@ -56,6 +56,7 @@ def weight_stationary_matmul(x, w, *, bm: int = 256, bn: int = 256,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda j, i: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nn * bn), out_dtype),
+        name="matmul",
         interpret=interpret,
     )(x, w)
     return out[:m, :n]

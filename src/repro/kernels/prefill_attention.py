@@ -280,6 +280,7 @@ def _paged_prefill(q, k_pages, v_pages, block_table, q_offset, length, *,
             jax.ShapeDtypeStruct((kvh, nqt * t * g, 1), jnp.float32),
             jax.ShapeDtypeStruct((kvh, nqt * t * g, 1), jnp.float32),
         ],
+        name="paged_prefill_attention",
         interpret=interpret,
     )(*prefetch, qh, k_pages, v_pages)
     out = out[:, :c * g]

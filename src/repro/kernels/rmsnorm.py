@@ -57,6 +57,7 @@ def rmsnorm(x, w, *, eps: float = 1e-5, block_rows: int = 256,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * block_rows, d), x.dtype),
+        name="rmsnorm",
         interpret=interpret,
     )(x2, w)
     return out[:rows].reshape(shape)

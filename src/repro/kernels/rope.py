@@ -75,6 +75,7 @@ def apply_rope(x, positions, *, theta: float = 10_000.0, block_s: int = 16,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * block_rows, d), x.dtype),
+        name="rope",
         interpret=interpret,
     )(x2, pos2)
     return out[:rows].reshape(b, s, h, d)

@@ -103,6 +103,7 @@ def rwkv6_chunked(r, k, v, w, u, *, chunk: int = 32, interpret: bool = False):
             jax.ShapeDtypeStruct((b * h, d, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
+        name="rwkv_chunk_scan",
         interpret=interpret,
     )(rr, kk, vv, ww, u)
 

@@ -81,6 +81,7 @@ def silu_mul(gate, up, *, block_rows=None, curry_rounds: int = 0,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * block_rows, d), up.dtype),
+        name="silu_mul",
         interpret=interpret,
     )(g2, u2)
     return out[:rows].reshape(shape)

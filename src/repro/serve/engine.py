@@ -151,6 +151,26 @@ from repro.serve.expert_cache import ExpertCache
 LATENCY_CLASSES = ("interactive", "batch")
 CLASS_WEIGHTS = {"interactive": 8.0, "batch": 1.0}
 
+# Profiler spans (``jax.profiler.TraceAnnotation``) opened inside
+# ``ServeEngine.step``; with no trace active each costs a flag check.  They
+# land on the profiler's host plane, on the device planes' clock, so a
+# trace reduction can put each idle gap of the device down to a phase by
+# the innermost span covering it.  Nesting says what caused what:
+# ``serve.sample`` inside ``serve.prefill`` is that chunk's first token,
+# ``serve.retire`` inside ``serve.sample`` a request that just finished.
+#   span           covers                                    metadata
+#   serve.step     the whole tick                            tick
+#   serve.admit    page reservations before admission, then  queued
+#                  restores, prefix attach, swap-in, handoffs
+#   serve.prefill  one chunk: inputs, upload, dispatch       rid, bucket,
+#                                                            tokens, offset
+#   serve.decode   decode inputs, upload, dispatch           rows
+#   serve.sample   host sampling: its first read waits for   rows
+#                  the program it samples
+#   serve.retire   finish / preemption of one request        rid
+SPANS = ("serve.step", "serve.admit", "serve.prefill", "serve.decode",
+         "serve.sample", "serve.retire")
+
 
 @dataclass
 class Request:
@@ -955,6 +975,10 @@ class ServeEngine:
             # effective deadline (per-request deadline_ms, else the
             # class_deadlines_ms entry for their class)
             "handoffs": 0, "handoff_stalls": 0, "slo_violations": 0,
+            # queue wait: seconds from submit to a fresh request's first
+            # admission (restores and handoffs are not counted), summed
+            # over the admissions counted
+            "queue_wait_s": 0.0, "admissions": 0,
         }
         self._prefill_fns: Dict[int, object] = {}
         self._decode = self._make_decode_fn()
@@ -1028,21 +1052,21 @@ class ServeEngine:
                 out_specs=(espec, sspec) + ((espec,) if estats else ()),
                 check_vma=False)
 
-            def f(params, state, toks, lens, tables, mask):
+            def serve_decode_step(params, state, toks, lens, tables, mask):
                 self.stats["decode_traces"] += 1
                 return smapped(params, state, toks, lens, tables, mask)
         elif not self.dense_baseline:
             # runner path, unsharded: tables is None for slot-state-only
             # families (no paged component to address)
-            def f(params, state, toks, lens, tables, mask):
+            def serve_decode_step(params, state, toks, lens, tables, mask):
                 self.stats["decode_traces"] += 1
                 return runner.decode(params, state, toks, lens, tables, mask,
                                      expert_stats=estats)
         else:
-            def f(params, state, toks, lens, tables, mask):
+            def serve_decode_step(params, state, toks, lens, tables, mask):
                 self.stats["decode_traces"] += 1
                 return M.decode_step(cfg, params, state, toks, lens)
-        return jax.jit(f)
+        return jax.jit(serve_decode_step)
 
     def _prefill_fn(self, bucket: int):
         """One compiled prefill per bucket, cached for the engine lifetime
@@ -1074,24 +1098,26 @@ class ServeEngine:
                 out_specs=(espec, sspec) + ((espec,) if estats else ()),
                 check_vma=False)
 
-            def f(params, state, toks, length, q_offset, bt_row, slot):
+            def serve_prefill_chunk(params, state, toks, length, q_offset,
+                                    bt_row, slot):
                 self.stats["prefill_traces"] += 1
                 return smapped(params, state, toks, length, q_offset, bt_row,
                                slot)
         elif not self.dense_baseline:
             estats = self._moe_stats
 
-            def f(params, state, toks, length, q_offset, bt_row, slot):
+            def serve_prefill_chunk(params, state, toks, length, q_offset,
+                                    bt_row, slot):
                 self.stats["prefill_traces"] += 1
                 return runner.prefill_chunk(params, state, toks, length,
                                             q_offset, bt_row, slot,
                                             expert_stats=estats)
         else:
-            def f(params, toks, lens):
+            def serve_prefill_chunk(params, toks, lens):
                 self.stats["prefill_traces"] += 1
                 one = M.init_decode_state(cfg, 1, max_seq, dtype=dtype)
                 return M.prefill(cfg, params, one, tokens=toks, lengths=lens)
-        fn = jax.jit(f)
+        fn = jax.jit(serve_prefill_chunk)
         self._prefill_fns[bucket] = fn
         return fn
 
@@ -1307,6 +1333,9 @@ class ServeEngine:
                 q.popleft()
             else:
                 q.popleft()
+                self.stats["queue_wait_s"] += (time.perf_counter()
+                                               - req._t_submit)
+                self.stats["admissions"] += 1
                 req.prefill_pos = 0
                 req.cached_len = 0
                 req._published = 0
@@ -1635,12 +1664,15 @@ class ServeEngine:
             for slot, req in pending[:1]:
                 plen = self._plen(req)
                 bucket = self._bucket(plen)
-                logits = self._run_prefill_chunk(slot, req, bucket, plen)
-                self.stats["prefill_tokens"] += plen
-                self.stats["padded_tokens"] += bucket
-                req.prefill_pos = plen
-                self.lengths[slot] = plen
-                self._finish_prefill(slot, req, logits, finished)
+                with jax.profiler.TraceAnnotation(
+                        "serve.prefill", rid=req.rid, bucket=bucket,
+                        tokens=plen, offset=0):
+                    logits = self._run_prefill_chunk(slot, req, bucket, plen)
+                    self.stats["prefill_tokens"] += plen
+                    self.stats["padded_tokens"] += bucket
+                    req.prefill_pos = plen
+                    self.lengths[slot] = plen
+                    self._finish_prefill(slot, req, logits, finished)
             return budget
         for slot, req in pending:
             plen = self._prefill_target(req)
@@ -1663,26 +1695,30 @@ class ServeEngine:
                     self.stats["stall_events"] += 1
                     self._stalled_this_tick = True
                     break                      # pool exhausted; wait
-                logits = self._run_prefill_chunk(slot, req, bucket, n)
-                budget -= bucket
-                self.stats["padded_tokens"] += bucket
-                self.stats["prefill_tokens"] += n
-                req.prefill_pos += n
-                self.lengths[slot] = req.prefill_pos
-                if self.prefix_caching:
-                    self._publish_pages(slot, req)
-                # a decode-phase restore that just completed discards the
-                # chunk's logits: the next decode feeds out_tokens[-1]
-                # (a sampled token is never re-sampled)
-                if req.prefill_pos >= plen and not req.out_tokens:
-                    self._finish_prefill(slot, req, logits, finished)
+                with jax.profiler.TraceAnnotation(
+                        "serve.prefill", rid=req.rid, bucket=bucket,
+                        tokens=n, offset=req.prefill_pos):
+                    logits = self._run_prefill_chunk(slot, req, bucket, n)
+                    budget -= bucket
+                    self.stats["padded_tokens"] += bucket
+                    self.stats["prefill_tokens"] += n
+                    req.prefill_pos += n
+                    self.lengths[slot] = req.prefill_pos
+                    if self.prefix_caching:
+                        self._publish_pages(slot, req)
+                    # a decode-phase restore that just completed discards
+                    # the chunk's logits: the next decode feeds
+                    # out_tokens[-1] (a sampled token is never re-sampled)
+                    if req.prefill_pos >= plen and not req.out_tokens:
+                        self._finish_prefill(slot, req, logits, finished)
         return budget
 
     def _finish_prefill(self, slot: int, req: Request, logits,
                         finished: List[Request]) -> None:
         """Prompt fully cached: sample the first token; retire immediately
         on EOS / single-token requests."""
-        first = self._sample(logits[0], req)
+        with jax.profiler.TraceAnnotation("serve.sample", rows=1):
+            first = self._sample(logits[0], req)
         req.out_tokens.append(int(first))
         req._t_first = time.perf_counter()
         req.ttft = req._t_first - req._t_submit
@@ -1700,25 +1736,26 @@ class ServeEngine:
                 ) -> None:
         """Retire a completed request: latency bookkeeping (wall + tick
         clocks), per-class goodput accounting, slot/page recycling."""
-        req.done = True
-        req.finish_tick = self._tick
-        if len(req.out_tokens) > 1 and req._t_first:
-            req.tpot = ((time.perf_counter() - req._t_first)
-                        / (len(req.out_tokens) - 1))
-        cs = self.class_stats[req.priority]
-        cs["finished"] += 1
-        cs["finished_tokens"] += len(req.out_tokens)
-        # SLO accounting: per-request deadline_ms overrides the class
-        # default; violations are counted at finish on the wall clock
-        # (submit -> last token), the latency the caller actually saw
-        dl = (req.deadline_ms if req.deadline_ms is not None
-              else self.class_deadlines_ms.get(req.priority))
-        if dl is not None and req._t_submit:
-            if (time.perf_counter() - req._t_submit) * 1e3 > dl:
-                self.stats["slo_violations"] += 1
-                cs["slo_violations"] += 1
-        finished.append(req)
-        self._retire(slot)
+        with jax.profiler.TraceAnnotation("serve.retire", rid=req.rid):
+            req.done = True
+            req.finish_tick = self._tick
+            if len(req.out_tokens) > 1 and req._t_first:
+                req.tpot = ((time.perf_counter() - req._t_first)
+                            / (len(req.out_tokens) - 1))
+            cs = self.class_stats[req.priority]
+            cs["finished"] += 1
+            cs["finished_tokens"] += len(req.out_tokens)
+            # SLO accounting: per-request deadline_ms overrides the class
+            # default; violations are counted at finish on the wall clock
+            # (submit -> last token), the latency the caller actually saw
+            dl = (req.deadline_ms if req.deadline_ms is not None
+                  else self.class_deadlines_ms.get(req.priority))
+            if dl is not None and req._t_submit:
+                if (time.perf_counter() - req._t_submit) * 1e3 > dl:
+                    self.stats["slo_violations"] += 1
+                    cs["slo_violations"] += 1
+            finished.append(req)
+            self._retire(slot)
 
     def _run_prefill_chunk(self, slot: int, req: Request, bucket: int,
                            n: int):
@@ -1815,6 +1852,39 @@ class ServeEngine:
         self.rng, sub = jax.random.split(self.rng)
         return int(jax.random.categorical(sub, logits / req.temperature))
 
+    def _dispatch_decode(self, runnable: List[int]):
+        """Upload the decode inputs of the ``runnable`` slots and dispatch
+        one batched decode step; returns its logits (not waited for)."""
+        toks = np.zeros((self.slots,), np.int32)
+        mask = np.zeros((self.slots,), bool)
+        for i in runnable:
+            toks[i] = self.active[i].out_tokens[-1]
+            mask[i] = True
+        # .copy(): jnp.asarray zero-copy-aliases numpy buffers on CPU, and
+        # lengths/table are mutated below while the async dispatch may
+        # still be reading them (shard_local already builds a fresh array)
+        if not self.paged:
+            tables = None
+        elif self.seq_shards > 1:
+            tables = jnp.asarray(self.alloc.shard_local(self.alloc.table))
+            self._account_noc_combine(rows=self.slots)
+        else:
+            tables = jnp.asarray(self.alloc.table.copy())
+        # the mask gates recurrent slot-state updates: batched decode must
+        # not advance a mid-prefill neighbour's state
+        out = self._decode(
+            self.params, self.state, jnp.asarray(toks),
+            jnp.asarray(self.lengths.copy()), tables, jnp.asarray(mask))
+        if self._moe_stats:
+            logits, self.state, est = out
+            # the batched dispatch routes every slot row (masked
+            # neighbours included), so the whole batch counts:
+            # sum(expert_load) == n_layers * top_k * routed_tokens
+            self._account_expert(est, rows=self.slots)
+        else:
+            logits, self.state = out
+        return logits
+
     # -- engine tick ---------------------------------------------------
     def _decode_ready(self, slot: int) -> bool:
         """Decode may run only once the FULL prefill target is cached —
@@ -1841,8 +1911,13 @@ class ServeEngine:
         class weight) — its progress is preserved (swap or recompute, per
         ``preempt_policy``) and it re-admits with priority.  With
         ``proactive_horizon > 0`` the same eviction fires *before* the
-        stall, off the predicted page demand."""
+        stall, off the predicted page demand.  The tick's phases open the
+        profiler spans listed in ``SPANS``."""
         self._tick += 1
+        with jax.profiler.TraceAnnotation("serve.step", tick=self._tick):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         self.stats["ticks"] += 1
         self._stalled_this_tick = False
         progress0 = self.stats["prefill_tokens"] + self.stats["decode_tokens"]
@@ -1855,11 +1930,12 @@ class ServeEngine:
         # restore or admission can take it: a swap-in that consumed exactly
         # the pages its own preemption freed would re-starve the survivors
         # and ping-pong the pool forever
-        if self.paged:
-            for i in range(self.slots):
-                if self._decode_ready(i):
-                    self.alloc.ensure(i, self.lengths[i] + 1)
-        self._admit()
+        with jax.profiler.TraceAnnotation("serve.admit", queued=self.queued):
+            if self.paged:
+                for i in range(self.slots):
+                    if self._decode_ready(i):
+                        self.alloc.ensure(i, self.lengths[i] + 1)
+            self._admit()
         finished: List[Request] = []
         decode_slots = [i for i in range(self.slots) if self._decode_ready(i)]
         # decode is never starved: its tokens are reserved before prefill,
@@ -1896,48 +1972,24 @@ class ServeEngine:
                     continue                   # stalled: re-decoded later
                 runnable.append(i)
             if runnable:
-                toks = np.zeros((self.slots,), np.int32)
-                mask = np.zeros((self.slots,), bool)
-                for i in runnable:
-                    toks[i] = self.active[i].out_tokens[-1]
-                    mask[i] = True
-                # .copy(): jnp.asarray zero-copy-aliases numpy buffers on
-                # CPU, and lengths/table are mutated below while the async
-                # dispatch may still be reading them (shard_local already
-                # builds a fresh array)
-                if not self.paged:
-                    tables = None
-                elif self.seq_shards > 1:
-                    tables = jnp.asarray(
-                        self.alloc.shard_local(self.alloc.table))
-                    self._account_noc_combine(rows=self.slots)
-                else:
-                    tables = jnp.asarray(self.alloc.table.copy())
-                # the mask gates recurrent slot-state updates: batched
-                # decode must not advance a mid-prefill neighbour's state
-                out = self._decode(
-                    self.params, self.state, jnp.asarray(toks),
-                    jnp.asarray(self.lengths.copy()), tables,
-                    jnp.asarray(mask))
-                if self._moe_stats:
-                    logits, self.state, est = out
-                    # the batched dispatch routes every slot row (masked
-                    # neighbours included), so the whole batch counts:
-                    # sum(expert_load) == n_layers * top_k * routed_tokens
-                    self._account_expert(est, rows=self.slots)
-                else:
-                    logits, self.state = out
-                for i in runnable:
-                    req = self.active[i]
-                    self.lengths[i] += 1
-                    self.stats["decode_tokens"] += 1
-                    self.stats["padded_tokens"] += 1
-                    nxt = self._sample(logits[i], req)
-                    req.out_tokens.append(nxt)
-                    hit_eos = req.eos_id is not None and nxt == req.eos_id
-                    if (len(req.out_tokens) >= req.max_new_tokens or hit_eos
-                            or self.lengths[i] >= self.max_seq - 1):
-                        self._finish(i, req, finished)
+                with jax.profiler.TraceAnnotation("serve.decode",
+                                                  rows=len(runnable)):
+                    logits = self._dispatch_decode(runnable)
+                with jax.profiler.TraceAnnotation("serve.sample",
+                                                  rows=len(runnable)):
+                    for i in runnable:
+                        req = self.active[i]
+                        self.lengths[i] += 1
+                        self.stats["decode_tokens"] += 1
+                        self.stats["padded_tokens"] += 1
+                        nxt = self._sample(logits[i], req)
+                        req.out_tokens.append(nxt)
+                        hit_eos = (req.eos_id is not None
+                                   and nxt == req.eos_id)
+                        if (len(req.out_tokens) >= req.max_new_tokens
+                                or hit_eos
+                                or self.lengths[i] >= self.max_seq - 1):
+                            self._finish(i, req, finished)
         if self.paged:
             for k in ("pages_allocated", "pages_freed", "pages_shared",
                       "pages_evicted"):
@@ -2079,44 +2131,45 @@ class ServeEngine:
         to move vs prefill FLOPs to replay.  Either way the request lands
         in ``restore_queue`` with priority over new admissions."""
         req = self.active[slot]
-        L = int(self.lengths[slot])    # KV rows live right now
-        self.stats["preemptions"] += 1
-        self.stats["preempted_tokens"] += L
-        self.class_stats[req.priority]["preemptions"] += 1
-        req._preempted_live = L
-        if L == 0:                      # nothing cached yet: plain requeue
+        with jax.profiler.TraceAnnotation("serve.retire", rid=req.rid):
+            L = int(self.lengths[slot])    # KV rows live right now
+            self.stats["preemptions"] += 1
+            self.stats["preempted_tokens"] += L
+            self.class_stats[req.priority]["preemptions"] += 1
+            req._preempted_live = L
+            if L == 0:                      # nothing cached yet: plain requeue
+                req.prefill_pos = 0
+                self._retire(slot)
+                self.restore_queue.append(req)
+                return
+            plen = self._plen(req)
+            if req.out_tokens:
+                # resume target: every decoded token except the still-unfed
+                # last sample must be back in KV before decode continues.  L
+                # can sit BELOW this (a victim preempted again mid-restore) —
+                # the gap is covered by _resume_tokens either way.
+                target = plen + len(req.out_tokens) - 1
+                kv_seq = np.concatenate([
+                    req.prompt[:plen].astype(np.int32),
+                    np.asarray(req.out_tokens[:-1], np.int32)])
+            else:
+                target = 0                  # plain prompt prefill resumes it
+                kv_seq = req.prompt[:plen].astype(np.int32)
+            policy = self._preempt_choice(req, L)
+            if policy == "swap" and not self._swap_out(slot, L):
+                policy = "recompute"        # arena full: degrade, never fail
+            if policy == "swap":
+                self.stats["preempt_swaps"] += 1
+            else:
+                self.stats["preempt_recomputes"] += 1
+                if self.prefix_caching:
+                    self._extend_digests(req, kv_seq)
+                    self._publish_resume_pages(slot, req, L)
+            req.resume_len = target
+            req._resume_tokens = kv_seq
             req.prefill_pos = 0
             self._retire(slot)
             self.restore_queue.append(req)
-            return
-        plen = self._plen(req)
-        if req.out_tokens:
-            # resume target: every decoded token except the still-unfed
-            # last sample must be back in KV before decode continues.  L
-            # can sit BELOW this (a victim preempted again mid-restore) —
-            # the gap is covered by _resume_tokens either way.
-            target = plen + len(req.out_tokens) - 1
-            kv_seq = np.concatenate([
-                req.prompt[:plen].astype(np.int32),
-                np.asarray(req.out_tokens[:-1], np.int32)])
-        else:
-            target = 0                  # plain prompt prefill resumes it
-            kv_seq = req.prompt[:plen].astype(np.int32)
-        policy = self._preempt_choice(req, L)
-        if policy == "swap" and not self._swap_out(slot, L):
-            policy = "recompute"        # arena full: degrade, never fail
-        if policy == "swap":
-            self.stats["preempt_swaps"] += 1
-        else:
-            self.stats["preempt_recomputes"] += 1
-            if self.prefix_caching:
-                self._extend_digests(req, kv_seq)
-                self._publish_resume_pages(slot, req, L)
-        req.resume_len = target
-        req._resume_tokens = kv_seq
-        req.prefill_pos = 0
-        self._retire(slot)
-        self.restore_queue.append(req)
 
     def _preempt_choice(self, req: Request, live_tokens: int) -> str:
         if self.preempt_policy != "auto":

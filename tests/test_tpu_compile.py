@@ -58,6 +58,7 @@ def spec(topo):
 def _assert_kernel(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def _pool(spec, hd, bs, quantized):
@@ -141,3 +142,33 @@ def test_mamba2_chunk_scan(spec):
                    spec((1, seq, heads, p)), spec((1, seq, heads), jnp.float32),
                    spec((heads,), jnp.float32), spec((1, seq, n)),
                    spec((1, seq, n)))
+
+
+_NAMED = {
+    "paged_decode_attention": lambda spec: (
+        lambda q, kp, vp, bt, lens: da.paged_decode_attention(
+            q, kp, vp, bt, lengths=lens),
+        spec((SLOTS, HEADS, 64)), *_pool(spec, 64, 16, False)[0],
+        spec((SLOTS, MAX_SEQ // 16), jnp.int32), spec((SLOTS,), jnp.int32)),
+    "paged_prefill_attention": lambda spec: (
+        lambda q, kp, vp, bt, off, n: pf.paged_prefill_attention(
+            q, kp, vp, bt, q_offset=off, length=n),
+        spec((1, 128, HEADS, 64)), *_pool(spec, 64, 16, False)[0],
+        spec((MAX_SEQ // 16,), jnp.int32), spec((), jnp.int32),
+        spec((), jnp.int32)),
+    "rmsnorm": lambda spec: (rn.rmsnorm, spec((SLOTS, 2048)), spec((2048,))),
+    "silu_mul": lambda spec: (swiglu.silu_mul, spec((SLOTS, 5632)),
+                              spec((SLOTS, 5632))),
+    "rope": lambda spec: (lambda x, pos: rope.apply_rope(x, pos),
+                          spec((SLOTS, 1, HEADS, 64)),
+                          spec((SLOTS, 1), jnp.int32)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_NAMED))
+def test_kernel_is_named(spec, name):
+    """Each serving kernel carries its ``name=`` into the compiled program
+    (the instruction and its op name), so a trace can find it by name."""
+    text = _assert_kernel(*_NAMED[name](spec))
+    assert f"%{name}." in text
+    assert f"/{name}/pallas_call" in text
